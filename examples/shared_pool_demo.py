@@ -1,10 +1,12 @@
 """Shared memory-node demo: two trainers, one pool, per-tenant accounting.
 
-Starts a standalone pool-server (the memory node), then runs TWO trainer
-processes concurrently against it as different tenants ("trainer-a",
-"trainer-b"), each with a byte quota. When both finish, the parent connects
-as an operator and prints the per-tenant traffic/energy the node attributed
-to each trainer, then proves the isolation properties:
+Starts a standalone pool-server (the memory node), then trains TWO models
+against it as different tenants ("trainer-a", "trainer-b"), each with a
+byte quota and its own ``CheckpointManager``. Both run in this one process
+and step in turn: a chip belongs to one process at a time, so two trainer
+processes could not share a one-chip host. When both finish, the demo
+connects as an operator and prints the per-tenant traffic/energy the node
+attributed to each trainer, then proves the isolation properties:
 
   * a third tenant ("eve") cannot read either trainer's domains — raw-offset
     access outside its owned regions raises ``TenantIsolationError``;
@@ -20,35 +22,46 @@ import sys
 ROOT = "/tmp/repro_shared_pool_demo"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 QUOTA = 64 << 20
+STEPS = 8
 
-TRAINER = r"""
-import sys, jax
-sys.path.insert(0, "src")
-from repro.configs import get_arch
-from repro.configs.base import CheckpointConfig, TrainConfig
-from repro.core.checkpoint.manager import CheckpointManager
-from repro.data.synthetic import make_batches
-from repro.training import train_loop
 
-tenant = %(tenant)r
-b = get_arch("dlrm-rm1", smoke=True)
-# max_undo_logs trimmed so the undo ring fits the per-tenant byte budget
-# (the default 64-slot ring alone would blow a 64 MiB quota for this model)
-cc = CheckpointConfig(directory=%(ckpt)r, dense_interval=4,
-                      pool_backend="remote", pool_addr=%(addr)r,
-                      pool_tenant=tenant, pool_quota=%(quota)d,
-                      max_undo_logs=8)
-tc = TrainConfig(learning_rate=3e-4, embed_learning_rate=0.01, checkpoint=cc)
-data = make_batches(b.model, 16, 0, seed=%(seed)d)
-init_fn, _, _, _ = train_loop.make_step_fns(b.model, tc)
-st = init_fn(jax.random.PRNGKey(%(seed)d))
-mgr = CheckpointManager(b.model, cc, embed_init=st["embed"])
-train_loop.train(b.model, tc, data, %(steps)d, relaxed=True, state=st,
-                 ckpt_manager=mgr)
-mgr.flush()
-print(f"[{tenant}] done: {mgr.stats}", flush=True)
-mgr.close()
-"""
+def train_tenants(addr: str):
+    """Two tenants, two managers on one memory node, stepping in turn."""
+    import jax
+
+    from repro.configs import get_arch
+    from repro.configs.base import CheckpointConfig, TrainConfig
+    from repro.core.checkpoint.manager import CheckpointManager
+    from repro.data.synthetic import make_batches
+    from repro.training import train_loop
+
+    b = get_arch("dlrm-rm1", smoke=True)
+    tc = TrainConfig(learning_rate=3e-4, embed_learning_rate=0.01)
+    init_fn, _, relaxed_step, warmup = train_loop.make_step_fns(b.model, tc)
+    step, warm = jax.jit(relaxed_step), jax.jit(warmup)
+    tenants = []
+    for seed, tenant in enumerate(("trainer-a", "trainer-b")):
+        # max_undo_logs trimmed so the undo ring fits the per-tenant byte
+        # budget (the default 64-slot ring alone would blow a 64 MiB quota
+        # for this model)
+        cc = CheckpointConfig(directory=os.path.join(ROOT, tenant),
+                              dense_interval=4, pool_backend="remote",
+                              pool_addr=addr, pool_tenant=tenant,
+                              pool_quota=QUOTA, max_undo_logs=8)
+        data = make_batches(b.model, 16, 0, seed=seed)
+        st = warm(init_fn(jax.random.PRNGKey(seed)), data.next(0))
+        mgr = CheckpointManager(b.model, cc, embed_init=st["embed"])
+        tenants.append({"name": tenant, "state": st, "data": data,
+                        "mgr": mgr})
+    for n in range(STEPS):
+        for t in tenants:
+            t["state"], m = step(t["state"], t["data"].next(n),
+                                 t["data"].next(n + 1))
+            t["mgr"].on_step(n, t["state"], m["ckpt_feed"])
+    for t in tenants:
+        t["mgr"].flush()
+        print(f"[{t['name']}] done: {t['mgr'].stats}", flush=True)
+        t["mgr"].close()
 
 
 def main():
@@ -65,25 +78,10 @@ def main():
     print(" ", line)
     assert "listening" in line, f"server failed to start: {line}"
 
-    print("== launching two trainer tenants concurrently ==")
-    trainers = []
-    for i, tenant in enumerate(("trainer-a", "trainer-b")):
-        code = TRAINER % {"tenant": tenant, "addr": addr, "quota": QUOTA,
-                          "ckpt": os.path.join(ROOT, tenant), "seed": i,
-                          "steps": 8}
-        trainers.append((tenant, subprocess.Popen(
-            [sys.executable, "-c", code], stdout=subprocess.PIPE,
-            text=True, cwd=REPO)))
-    failed = False
-    for tenant, proc in trainers:
-        out, _ = proc.communicate()
-        print(out.strip())
-        if proc.returncode != 0:
-            print(f"[{tenant}] FAILED rc={proc.returncode}")
-            failed = True
-    assert not failed, "a trainer tenant failed"
-
     sys.path.insert(0, os.path.join(REPO, "src"))
+    print("== training two tenants in turn, one process ==")
+    train_tenants(addr)
+
     from repro.pool import (PoolMetrics, QuotaExceededError, RemotePool,
                             TenantIsolationError)
 

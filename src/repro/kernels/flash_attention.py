@@ -60,7 +60,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, bq: int = 128,
-                           bk: int = 128, interpret: bool = True):
+                           bk: int = 128, interpret: bool):
     """q,k,v: (BH, S, D) — batch*heads flattened, same head count (GQA
     expansion by caller). Returns (BH, S, D)."""
     BH, Sq, D = q.shape

@@ -6,6 +6,11 @@ Backend selection:
   * "pallas_interpret" — Pallas kernels executed in interpret mode (CPU
                          validation of kernel logic)
   * "pallas"           — compiled Pallas (the TPU target)
+
+Every call takes ONE table. The kernels scalar-prefetch their int32 index
+operands into SMEM (1 MiB on TPU v5e), so all tables of a model in one call
+would overflow it (RM1: 20 tables x 256 x 80 ids = 1.6 MB per operand);
+``_check_smem`` refuses such a call before the compiler does.
 """
 from __future__ import annotations
 
@@ -19,6 +24,9 @@ from repro.kernels import ref
 from repro.kernels import scatter_update as su
 
 _state = threading.local()
+
+SMEM_BYTES = 1 << 20        # scalar memory of one TPU v5e core
+SMEM_RESERVE = 16 << 10     # left for the kernel's own scalars
 
 
 def set_backend(name: str):
@@ -39,11 +47,23 @@ def _pad_lanes(x, mult: int = 128):
     return jnp.pad(x, widths), d
 
 
+def _check_smem(n: int, operands: int):
+    """The ``operands`` prefetched int32 arrays of length ``n`` must fit in
+    SMEM beside the reserve; split the call (one table, fewer ids) if not."""
+    need = operands * 4 * n
+    if need + SMEM_RESERVE > SMEM_BYTES:
+        raise ValueError(
+            f"{operands} scalar-prefetch operand(s) of {n} int32 ids need "
+            f"{need} B of SMEM; at most {SMEM_BYTES - SMEM_RESERVE} B fit — "
+            f"call the kernel once per table with fewer ids")
+
+
 def embedding_bag(table, idx, seg, num_bags: int):
     """Fused gather + segment-sum. idx/seg (N,), seg non-decreasing."""
     backend = get_backend()
     if backend == "xla":
         return ref.embedding_bag_ref(table, idx, seg, num_bags)
+    _check_smem(idx.shape[0], operands=2)
     tp, d = _pad_lanes(table)
     out = eb.embedding_bag_pallas(tp, idx, seg, num_bags,
                                   interpret=(backend == "pallas_interpret"))
@@ -54,6 +74,7 @@ def gather_rows(table, idx):
     backend = get_backend()
     if backend == "xla":
         return jnp.take(table, idx, axis=0)
+    _check_smem(idx.shape[0], operands=1)
     tp, d = _pad_lanes(table)
     out = eb.gather_rows_pallas(tp, idx,
                                 interpret=(backend == "pallas_interpret"))
@@ -86,6 +107,7 @@ def scatter_update(table, idx, delta):
     backend = get_backend()
     if backend == "xla":
         return ref.scatter_update_ref(table, idx, delta)
+    _check_smem(idx.shape[0], operands=1)
     tp, d = _pad_lanes(table)
     dp, _ = _pad_lanes(delta)
     out = su.scatter_update_pallas(tp, idx, dp,
@@ -98,6 +120,7 @@ def scatter_update_logged(table, idx, delta):
     backend = get_backend()
     if backend == "xla":
         return ref.scatter_update_logged_ref(table, idx, delta)
+    _check_smem(idx.shape[0], operands=1)
     tp, d = _pad_lanes(table)
     dp, _ = _pad_lanes(delta)
     new_t, old = su.scatter_update_logged_pallas(

@@ -8,7 +8,8 @@ embedding table in the data region can be directly updated").
 
 Constraint: ``idx`` must be unique (duplicates pre-combined by the caller via
 segment-sum, as in production sparse-core updates); ops.py provides the
-combine helper. D padded to a lane multiple by ops.py.
+combine helper. D padded to a lane multiple by ops.py. Rows move as
+``(None, 1, D)`` blocks of an ``(R, 1, D)`` view (see embedding_bag.py).
 """
 from __future__ import annotations
 
@@ -16,12 +17,14 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.embedding_bag import row_spec, row_view
+
 
 def _update_kernel(idx_ref, delta_ref, row_ref, out_ref):
     out_ref[...] = row_ref[...] + delta_ref[...].astype(row_ref.dtype)
 
 
-def scatter_update_pallas(table, idx, delta, *, interpret: bool = True):
+def scatter_update_pallas(table, idx, delta, *, interpret: bool):
     """table: (R, D); idx: (N,) unique; delta: (N, D). Rows += delta in place.
 
     Aliasing: the table is donated; untouched rows pass through because every
@@ -29,23 +32,24 @@ def scatter_update_pallas(table, idx, delta, *, interpret: bool = True):
     by construction — only touched blocks are visited, others remain).
     """
     n = idx.shape[0]
-    D = table.shape[1]
+    R, D = table.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, D), lambda i, idx_ref: (i, 0)),          # delta
-            pl.BlockSpec((1, D), lambda i, idx_ref: (idx_ref[i], 0)),  # row in
+            row_spec(D, lambda i, idx_ref: i),                 # delta
+            row_spec(D, lambda i, idx_ref: idx_ref[i]),        # row in
         ],
-        out_specs=pl.BlockSpec((1, D), lambda i, idx_ref: (idx_ref[i], 0)),
+        out_specs=row_spec(D, lambda i, idx_ref: idx_ref[i]),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _update_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, 1, D), table.dtype),
         input_output_aliases={2: 0},               # table -> out (in-place)
         interpret=interpret,
-    )(idx, delta, table)
+    )(idx, row_view(delta), row_view(table))
+    return out.reshape(R, D)
 
 
 def _update_logged_kernel(idx_ref, delta_ref, row_ref, out_ref, log_ref):
@@ -53,27 +57,28 @@ def _update_logged_kernel(idx_ref, delta_ref, row_ref, out_ref, log_ref):
     out_ref[...] = row_ref[...] + delta_ref[...].astype(row_ref.dtype)
 
 
-def scatter_update_logged_pallas(table, idx, delta, *, interpret: bool = True):
+def scatter_update_logged_pallas(table, idx, delta, *, interpret: bool):
     """Fused update + undo-log capture. Returns (new_table, old_rows)."""
     n = idx.shape[0]
-    D = table.shape[1]
+    R, D = table.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, D), lambda i, idx_ref: (i, 0)),
-            pl.BlockSpec((1, D), lambda i, idx_ref: (idx_ref[i], 0)),
+            row_spec(D, lambda i, idx_ref: i),
+            row_spec(D, lambda i, idx_ref: idx_ref[i]),
         ],
         out_specs=[
-            pl.BlockSpec((1, D), lambda i, idx_ref: (idx_ref[i], 0)),
-            pl.BlockSpec((1, D), lambda i, idx_ref: (i, 0)),
+            row_spec(D, lambda i, idx_ref: idx_ref[i]),
+            row_spec(D, lambda i, idx_ref: i),
         ],
     )
-    return pl.pallas_call(
+    new_t, old = pl.pallas_call(
         _update_logged_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(table.shape, table.dtype),
-                   jax.ShapeDtypeStruct((n, D), table.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((R, 1, D), table.dtype),
+                   jax.ShapeDtypeStruct((n, 1, D), table.dtype)],
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(idx, delta, table)
+    )(idx, row_view(delta), row_view(table))
+    return new_t.reshape(R, D), old.reshape(n, D)

@@ -63,7 +63,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *,
 
 
 def wkv6_pallas(r, k, v, logw, u, *, chunk: int = 16,
-                interpret: bool = True):
+                interpret: bool):
     """r,k,v,logw: (B, S, H, K); u: (H, K). Returns y: (B, S, H, K).
 
     Zero initial state (the train-step case; decode carries state in JAX).

@@ -24,6 +24,7 @@ from repro.configs import get_arch
 from repro.data.synthetic import make_batches
 from repro.models.registry import get_api
 from repro.training.serve_loop import greedy_generate, pool_serving
+from repro.utils.compile_cache import use_compile_cache
 
 
 def _build_tier(args, params):
@@ -48,6 +49,7 @@ def _build_tier(args, params):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--batch", type=int, default=4)

@@ -3,6 +3,9 @@
     PYTHONPATH=src python -m repro.launch.train --arch dlrm-rm1 --smoke \
         --steps 100 [--strict] [--ckpt-dir /tmp/ckpt] [--resume]
 
+``--full --batch 256`` trains at the configuration's published width (RM1:
+20 tables x 1M rows x 32, 80 lookups per table per sample).
+
 Runs the relaxed (paper) schedule by default with the two-tier asynchronous
 checkpoint manager; ``--resume`` recovers from the checkpoint directory
 (works across device counts — elastic restart).
@@ -22,9 +25,11 @@ from repro.core.checkpoint.manager import CheckpointManager
 from repro.data.synthetic import make_batches
 from repro.data.lookahead import LookaheadIterator
 from repro.training import train_loop
+from repro.utils.compile_cache import CompileStats, use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dlrm-rm1")
     ap.add_argument("--smoke", action="store_true", default=True)
@@ -98,6 +103,11 @@ def main():
         ap.error("--pool-backend sharded needs --pool-shards addr1,addr2,... "
                  "(one pool server per memory node)")
 
+    devs = jax.devices()
+    dev = devs[0]
+    print(f"[train] device {dev.platform} {dev.device_kind} x{len(devs)}",
+          flush=True)
+    compiles = CompileStats()
     bundle = get_arch(args.arch, smoke=args.smoke)
     cfg = bundle.model
     ckpt = CheckpointConfig(enabled=bool(args.ckpt_dir),
@@ -139,14 +149,19 @@ def main():
     t0 = time.time()
 
     def on_metrics(n, m):
+        if n == start:
+            print(f"[train] first step: {compiles}", flush=True)
         if n % 10 == 0:
             print(f"[train] step {n:5d} loss {float(m['loss']):.4f} "
-                  f"({(time.time()-t0):.1f}s)")
+                  f"({(time.time()-t0):.1f}s)", flush=True)
 
     state, losses = train_loop.train(
         cfg, tc, batches, args.steps, relaxed=not args.strict, state=state,
         start_step=start, ckpt_manager=mgr, on_metrics=on_metrics)
     print(f"[train] done: {len(losses)} steps, final loss {losses[-1]:.4f}")
+    mem = dev.memory_stats() or {}
+    print(f"[train] device peak_bytes_in_use "
+          f"{mem.get('peak_bytes_in_use', 'not reported')}")
     if mgr:
         print(f"[train] checkpoint stats: {mgr.stats}")
         print(mgr.pool.metrics.report())

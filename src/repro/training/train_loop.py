@@ -12,8 +12,8 @@ relaxed_step (TrainingCXL):
         commutative correction gather(U, idx_next)
     so no gather ever waits on a scatter: XLA can schedule the two prefetch
     gathers (and their psum, under the sharded pool) in parallel with the
-    backward pass. The undo-log content for the batch-aware checkpoint —
-    (idx_N, pre-update rows) — falls out of the same carry for free.
+    backward pass. The rows the batch-aware checkpoint logs are idx_N,
+    known from the batch before any compute.
 
 Both step functions are pure jit-able pytree->pytree maps; the checkpoint
 manager hooks observe their outputs from the host side.
@@ -116,10 +116,9 @@ def make_step_fns(cfg, train_cfg):
                      "opt_dense": od, "opt_embed": oe,
                      "step": state["step"] + 1,
                      "prefetch": {"rows": rows_next}}
-        # undo-log content for the batch-aware checkpoint: the pre-update rows
-        # of exactly the indices this batch touched (known in advance).
-        ckpt_feed = {"touched": rx.touched_indices(cfg, batch),
-                     "old_rows": rows_in, "delta": upd_e}
+        # the batch-aware checkpoint logs exactly the rows this batch touched
+        # (known in advance); the manager reads their new values from `embed`
+        ckpt_feed = {"touched": rx.touched_indices(cfg, batch)}
         return new_state, {"loss": loss, "grad_norm": gnorm,
                            "ckpt_feed": ckpt_feed}
 

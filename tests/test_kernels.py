@@ -100,6 +100,26 @@ def test_ops_backend_dispatch(rng):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
+@pytest.mark.parametrize("op,operands", [("embedding_bag", 2),
+                                         ("gather_rows", 1),
+                                         ("scatter_update", 1)])
+def test_ops_refuse_ids_past_smem(op, operands):
+    """One call's scalar-prefetched ids must fit in SMEM: the wrapper says
+    so before the TPU compiler runs out of it."""
+    n = (ops.SMEM_BYTES - ops.SMEM_RESERVE) // (4 * operands) + 1
+    table = jnp.zeros((8, 128), jnp.float32)
+    idx = jnp.zeros((n,), jnp.int32)
+    args = {"embedding_bag": (table, idx, idx, 4),
+            "gather_rows": (table, idx),
+            "scatter_update": (table, idx, jnp.zeros((n, 128)))}[op]
+    ops.set_backend("pallas_interpret")
+    try:
+        with pytest.raises(ValueError, match="SMEM"):
+            getattr(ops, op)(*args)
+    finally:
+        ops.set_backend("xla")
+
+
 @settings(deadline=None, max_examples=10)
 @given(n=st.integers(2, 40), rmax=st.integers(4, 64), seed=st.integers(0, 99))
 def test_property_combine_duplicates(n, rmax, seed):
@@ -124,7 +144,7 @@ def test_wkv6_pallas_kernel(rng, B, S, H, chunk):
         -np.exp(rng.standard_normal((B, S, H, K)) * 0.5 - 1)
         .astype(np.float32)), rw.LOG_W_MIN, -1e-4)
     u = jnp.asarray(rng.standard_normal((H, K)).astype(np.float32) * 0.3)
-    y_p = wkv6_pallas(r, k, v, logw, u, chunk=chunk)
+    y_p = wkv6_pallas(r, k, v, logw, u, chunk=chunk, interpret=True)
     y_r, _ = ref.wkv6_ref(r, k, v, logw, u,
                           jnp.zeros((B, H, K, K), jnp.float32))
     np.testing.assert_allclose(np.asarray(y_p), np.asarray(y_r),
