@@ -30,6 +30,16 @@ Tier-M (dense params, every K steps — the *relaxed batch-aware checkpoint*):
 
 All pool work runs on a background writer thread, off the critical path —
 ``on_step`` only enqueues. ``flush()`` drains (end of training / tests).
+
+Tracing. ``on_step`` marks its phases with profiler spans
+(``repro.ckpt.touched_to_host``, ``.row_gather``, ``.dense_to_host``,
+``.enqueue``); the writer thread marks each item (``repro.ckpt.tier_e``
+with ``.log_and_apply`` and ``.manifest``; ``repro.ckpt.tier_m`` with
+``.serialize`` and ``.blob_put``). Every span carries ``step=`` of the loop
+step that queued the work. ``stats["enqueue_wait_s"]`` sums the time the
+loop blocked for room in the queue; ``stats["queue_wait_s"]`` sums, over
+items, the time from the loop's hand-off (the ``put`` call) to the writer
+starting on the item.
 """
 from __future__ import annotations
 
@@ -41,6 +51,7 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.checkpoint import store
 from repro.core.checkpoint.undo_log import UndoRing
@@ -98,7 +109,8 @@ class CheckpointManager:
                       "replica_refresh_failures": 0,
                       "ship_steps": 0, "ship_link_bytes": 0,
                       "ship_full_refreshes": 0,
-                      "manifest_witness_failures": 0}
+                      "manifest_witness_failures": 0,
+                      "enqueue_wait_s": 0.0, "queue_wait_s": 0.0}
         self._commit_hooks: list = []
         self._man_witnesses: list = []
         self._ship_gen: Optional[int] = None
@@ -380,20 +392,29 @@ class CheckpointManager:
         self._raise_writer_err()
         if feed is None:   # strict mode: derive touched rows from the batch
             return
-        idx = flatten_touched(self.cfg, jax.device_get(feed["touched"]))
+        with TraceAnnotation("repro.ckpt.touched_to_host", step=step):
+            idx = flatten_touched(self.cfg, jax.device_get(feed["touched"]))
         # new row values: small device gather of exactly the touched rows
-        name, tab = _table_of(state["embed"])
-        flat_tab = tab.reshape(-1, tab.shape[-1])
-        new_rows = np.asarray(
-            jax.device_get(jnp_take(flat_tab, idx)), dtype=np.float32)
-        work = ("tier_e", step, idx, new_rows)
-        self._q.put(work)
+        with TraceAnnotation("repro.ckpt.row_gather", step=step):
+            name, tab = _table_of(state["embed"])
+            flat_tab = tab.reshape(-1, tab.shape[-1])
+            new_rows = np.asarray(
+                jax.device_get(jnp_take(flat_tab, idx)), dtype=np.float32)
+        self._enqueue("tier_e", step, (idx, new_rows))
         if (self.ccfg.dense_interval > 0
                 and step % self.ccfg.dense_interval == 0):
-            dense_np = jax.device_get(
-                {"dense": state["dense"], "opt_dense": state["opt_dense"],
-                 "opt_embed": state["opt_embed"]})
-            self._q.put(("tier_m", step, dense_np, time.monotonic()))
+            with TraceAnnotation("repro.ckpt.dense_to_host", step=step):
+                dense_np = jax.device_get(
+                    {"dense": state["dense"], "opt_dense": state["opt_dense"],
+                     "opt_embed": state["opt_embed"]})
+            self._enqueue("tier_m", step, dense_np)
+
+    def _enqueue(self, kind: str, step: int, payload):
+        """Hand an item to the writer; blocks while the queue is full."""
+        t0 = time.monotonic()
+        with TraceAnnotation("repro.ckpt.enqueue", step=step):
+            self._q.put((kind, step, payload, t0))
+        self.stats["enqueue_wait_s"] += time.monotonic() - t0
 
     def flush(self):
         self._q.join()
@@ -409,14 +430,16 @@ class CheckpointManager:
     # -- writer thread -------------------------------------------------------
     def _run(self):
         while True:
-            item = self._q.get()
+            kind, step, payload, t_enq = self._q.get()
             try:
                 if self._err is not None:
                     continue           # crashed: the machine is down
-                if item[0] == "tier_e":
-                    self._do_tier_e(*item[1:])
-                else:
-                    self._do_tier_m(*item[1:])
+                self.stats["queue_wait_s"] += time.monotonic() - t_enq
+                with TraceAnnotation(f"repro.ckpt.{kind}", step=step):
+                    if kind == "tier_e":
+                        self._do_tier_e(step, *payload)
+                    else:
+                        self._do_tier_m(step, payload, t_enq)
             except BaseException as e:  # surfaced on next on_step/flush
                 self._err = e
             finally:
@@ -427,13 +450,15 @@ class CheckpointManager:
         # apply, all inside the pool; only (step, idx, new_rows) crossed the
         # link to get here. The commit/apply crash window lives inside the
         # op (fault point "tier_e.between-commit-and-apply").
-        info = self.ring.log_and_apply(step, self.mirror_region, idx,
-                                       new_rows)
+        with TraceAnnotation("repro.ckpt.log_and_apply", step=step):
+            info = self.ring.log_and_apply(step, self.mirror_region, idx,
+                                           new_rows)
         self._hit("tier_e.between-apply-and-manifest")
         # 4: persistent step flag
-        man = self.manifest.read()
-        man["mirror_step"] = step
-        self._man_write(man, point="manifest-advance")
+        with TraceAnnotation("repro.ckpt.manifest", step=step):
+            man = self.manifest.read()
+            man["mirror_step"] = step
+            self._man_write(man, point="manifest-advance")
         self.ring.gc(step - self.ccfg.max_undo_logs)
         self.stats["tier_e"] += 1
         self.stats["bytes_e"] += idx.nbytes + new_rows.nbytes
@@ -449,7 +474,8 @@ class CheckpointManager:
                 and time.monotonic() - t_enq > self.ccfg.writer_deadline_s):
             self.stats["tier_m_skipped"] += 1      # relaxed ckpt: never block
             return
-        blob = store.serialize_tree(dense_np, {"step": step})
+        with TraceAnnotation("repro.ckpt.serialize", step=step):
+            blob = store.serialize_tree(dense_np, {"step": step})
         man = self.manifest.read()
         slot = 1 - man.get("dense_slot", 1)        # write the spare slot
         # the pool stores a framed (possibly compressed) image; size the
@@ -465,8 +491,9 @@ class CheckpointManager:
             region = self.dense_dom.alloc(
                 f"slot{slot}", shape=(int(cap * 1.5),), dtype="uint8")
         # compressed at the pool, persisted over exactly the written range
-        stored = self.nmp.blob_put(region, blob, compress=self.compress,
-                                   point="dense-blob")
+        with TraceAnnotation("repro.ckpt.blob_put", step=step):
+            stored = self.nmp.blob_put(region, blob, compress=self.compress,
+                                       point="dense-blob")
         man.update(dense_step=step, dense_slot=slot, dense_len=stored)
         self._man_write(man, point="manifest-dense")
         self.stats["tier_m"] += 1

@@ -6,6 +6,10 @@ feature interaction (pairwise dots) + concat -> top-MLP -> CTR logit.
 
 The embedding bags run through ``core.embedding_ops.bag_lookup`` — the
 near-data gather+reduce that is the heart of TrainingCXL.
+
+``forward`` names its phases with ``jax.named_scope`` (``bottom_mlp``,
+``interaction``, ``top_mlp``); the backward pass inherits them, so a device
+trace can split the step program's time by phase.
 """
 from __future__ import annotations
 
@@ -55,7 +59,8 @@ def init_dlrm(key, cfg):
 def forward(params, cfg, batch):
     """batch: dense (B, n_dense) float; sparse (B, T, L) int32 -> logits (B,)."""
     dense = batch["dense"].astype(cfg.activation_dtype)
-    z0 = _mlp_stack(params["bottom"], dense)                  # (B, d_emb)
+    with jax.named_scope("bottom_mlp"):
+        z0 = _mlp_stack(params["bottom"], dense)              # (B, d_emb)
     if batch.get("embed_rows") is not None:
         # relaxed lookup: reduced bag vectors prefetched at batch N-1
         bags = batch["embed_rows"]
@@ -63,12 +68,15 @@ def forward(params, cfg, batch):
         bags = embedding_ops.bag_lookup(params["embed"]["emb_tables"],
                                         batch["sparse"])      # (B, T, d_emb)
     bags = constrain(bags, ("batch", None, "embed"))
-    feats = jnp.concatenate([z0[:, None, :], bags.astype(z0.dtype)], axis=1)
-    inter = jnp.einsum("bnd,bmd->bnm", feats, feats)          # (B, F, F)
-    iu = jnp.triu_indices(feats.shape[1], k=1)
-    inter = inter[:, iu[0], iu[1]]                            # (B, F(F-1)/2)
-    x = jnp.concatenate([z0, inter.astype(z0.dtype)], axis=-1)
-    logit = _mlp_stack(params["top"], x, final_act=False)[:, 0]
+    with jax.named_scope("interaction"):
+        feats = jnp.concatenate([z0[:, None, :], bags.astype(z0.dtype)],
+                                axis=1)
+        inter = jnp.einsum("bnd,bmd->bnm", feats, feats)      # (B, F, F)
+        iu = jnp.triu_indices(feats.shape[1], k=1)
+        inter = inter[:, iu[0], iu[1]]                        # (B, F(F-1)/2)
+        x = jnp.concatenate([z0, inter.astype(z0.dtype)], axis=-1)
+    with jax.named_scope("top_mlp"):
+        logit = _mlp_stack(params["top"], x, final_act=False)[:, 0]
     return logit
 
 

@@ -13,13 +13,18 @@ file, so a SIGKILLed process recovers from disk exactly like a power-cycled
 PMEM module (``PmemPool.open``).
 
 Every access records (bytes, modeled latency) into ``PoolMetrics`` using the
-Table-2 device profiles from ``sim/devices.py``, and every persist barrier is
+Table-2 device profiles from ``sim/devices.py``; a persist also records its
+measured wall time (media writes plus sync) and marks itself with one
+profiler span, ``repro.pool.persist``. Every persist barrier is
 a named fault-injection point (see ``faults.py``): a schedule can drop it,
 tear it mid-range, or crash before/after it.
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import sys
+import time
 from typing import Optional
 
 import numpy as np
@@ -202,21 +207,25 @@ class PoolDevice:
             # the software *believes* this data is durable — media unchanged
             self.metrics.dropped_flushes += 1
             return
-        total = 0
-        for i, (s, e) in enumerate(todo):
-            if action == "torn" and i == 0:
-                half = s + max(1, (e - s) // 2)
-                self._media_write(s, self._cache[s:half])
-                self._media_sync()
-                self.metrics.torn_writes += 1
-                self.metrics.record("persist", half - s,
-                                    self.profile.t_bulk_write(half - s))
-                raise InjectedCrash(point, self.faults.counts.get(point, 0))
-            self._media_write(s, self._cache[s:e])
-            total += e - s
-        self._media_sync()
+        total = sum(e - s for s, e in todo)
+        t0 = time.perf_counter()
+        with _persist_span(total, len(todo)):
+            for i, (s, e) in enumerate(todo):
+                if action == "torn" and i == 0:
+                    half = s + max(1, (e - s) // 2)
+                    self._media_write(s, self._cache[s:half])
+                    self._media_sync()
+                    self.metrics.torn_writes += 1
+                    self.metrics.record("persist", half - s,
+                                        self.profile.t_bulk_write(half - s),
+                                        time.perf_counter() - t0)
+                    raise InjectedCrash(point,
+                                        self.faults.counts.get(point, 0))
+                self._media_write(s, self._cache[s:e])
+            self._media_sync()
         self.metrics.record("persist", total,
-                            self.profile.t_bulk_write(max(total, 1)))
+                            self.profile.t_bulk_write(max(total, 1)),
+                            time.perf_counter() - t0)
         if action == "crash-after":
             raise InjectedCrash(point, self.faults.counts.get(point, 0))
 
@@ -230,6 +239,16 @@ class PoolDevice:
 
     def close(self):
         self.closed = True
+
+
+def _persist_span(nbytes: int, ranges: int):
+    """The profiler span of one persist. Only a process that loaded JAX can
+    run the profiler, so a host-only pool server does not import it."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation("repro.pool.persist", bytes=nbytes,
+                                    ranges=ranges)
 
 
 class DramPool(PoolDevice):

@@ -25,14 +25,19 @@ LINK_W = 5.0  # matches sim/energy.py link term
 
 @dataclass
 class OpStat:
+    """Counts of one op kind: ``time_s`` is the device profile's modeled
+    time; ``wall_s`` the measured host time where the layer takes it
+    (``persist``: media writes plus sync), else 0."""
     ops: int = 0
     nbytes: int = 0
     time_s: float = 0.0
+    wall_s: float = 0.0
 
-    def add(self, nbytes: int, time_s: float):
+    def add(self, nbytes: int, time_s: float, wall_s: float = 0.0):
         self.ops += 1
         self.nbytes += int(nbytes)
         self.time_s += float(time_s)
+        self.wall_s += float(wall_s)
 
 
 @dataclass
@@ -94,8 +99,9 @@ class PoolMetrics:
         self.replica_refreshes += 1
         self.replica_bytes += int(nbytes)
 
-    def record(self, kind: str, nbytes: int, time_s: float):
-        self.media.setdefault(kind, OpStat()).add(nbytes, time_s)
+    def record(self, kind: str, nbytes: int, time_s: float,
+               wall_s: float = 0.0):
+        self.media.setdefault(kind, OpStat()).add(nbytes, time_s, wall_s)
 
     def record_link(self, kind: str, nbytes: int,
                     link: dv.Link = dv.CXL_LINK):
@@ -173,7 +179,8 @@ class PoolMetrics:
             for kind, st in (snap.get(side) or {}).items():
                 table[kind] = OpStat(ops=int(st["ops"]),
                                      nbytes=int(st["nbytes"]),
-                                     time_s=float(st["time_s"]))
+                                     time_s=float(st["time_s"]),
+                                     wall_s=float(st.get("wall_s", 0.0)))
         m.ndp_time_s = float(snap.get("ndp_time_s", 0.0))
         m.comp_raw_bytes = int(snap.get("comp_raw_bytes", 0))
         m.comp_stored_bytes = int(snap.get("comp_stored_bytes", 0))
@@ -230,8 +237,10 @@ class PoolMetrics:
         for side, table in (("media", self.media), ("link", self.link)):
             for kind in sorted(table):
                 s = table[kind]
+                wall = f" wall={s.wall_s * 1e3:.3f}ms" if s.wall_s else ""
                 lines.append(f"  {side:5s} {kind:14s} ops={s.ops:<7d} "
-                             f"bytes={s.nbytes:<12d} t={s.time_s * 1e3:.3f}ms")
+                             f"bytes={s.nbytes:<12d} t={s.time_s * 1e3:.3f}ms"
+                             + wall)
         e = self.energy()
         lines.append(f"  link/media byte ratio: "
                      f"{self.link_bytes() / max(1, self.media_bytes()):.4f}")

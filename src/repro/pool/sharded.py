@@ -249,6 +249,7 @@ def merge_metrics(snapshots: Sequence[dict],
                 t.ops += s.ops
                 t.nbytes += s.nbytes
                 t.time_s += s.time_s
+                t.wall_s += s.wall_s
         agg.ndp_time_s += m.ndp_time_s
         agg.comp_raw_bytes += m.comp_raw_bytes
         agg.comp_stored_bytes += m.comp_stored_bytes

@@ -17,6 +17,18 @@ relaxed_step (TrainingCXL):
 
 Both step functions are pure jit-able pytree->pytree maps; the checkpoint
 manager hooks observe their outputs from the host side.
+
+Tracing. The step programs name their phases with ``jax.named_scope``:
+``embed_grad`` (the lookup's adjoint), ``dense_update`` (clip, optimizer,
+apply), ``embed_update`` (optimizer, pool layout, apply), ``prefetch``
+(relaxed prefetch with correction) and ``ckpt_feed``; the model adds
+``bottom_mlp``, ``interaction`` and ``top_mlp`` (``models/dlrm.py``), which
+the backward pass inherits. Scopes are metadata only: the compiled program
+is the same with or without them. ``train()`` marks each iteration with a
+``StepTraceAnnotation`` (``repro.train.step``) and its host work with
+``TraceAnnotation`` spans carrying ``step=n``: ``repro.train.next_batch``,
+``.dispatch``, ``.loss_read``, ``.ckpt_on_step`` and ``.on_metrics``. All
+of them land in the profiler's own trace, on one clock with the device.
 """
 from __future__ import annotations
 
@@ -54,6 +66,20 @@ def make_step_fns(cfg, train_cfg):
         params = api.init(key, cfg)
         return st.make_state(params, dense_opt, embed_opt)
 
+    def dense_update(state, g_dense):
+        """Clip, optimizer and apply of the dense tier (both schedules)."""
+        with jax.named_scope("dense_update"):
+            if train_cfg.grad_clip:
+                g_dense, gnorm = opt.global_norm_clip(g_dense,
+                                                      train_cfg.grad_clip)
+            else:
+                gnorm = jnp.zeros(())
+            upd_d, od = dense_opt.update(g_dense, state["opt_dense"],
+                                         state["dense"])
+            dense = jax.tree.map(lambda p, u: (p.astype(jnp.float32) + u)
+                                 .astype(p.dtype), state["dense"], upd_d)
+        return dense, od, gnorm
+
     # -- strict ------------------------------------------------------------
     def strict_step(state, batch):
         def full_loss(dense, embed):
@@ -62,15 +88,11 @@ def make_step_fns(cfg, train_cfg):
         loss, grads = jax.value_and_grad(full_loss, argnums=(0, 1))(
             state["dense"], state["embed"])
         g_dense, g_embed = grads
-        if train_cfg.grad_clip:
-            g_dense, gnorm = opt.global_norm_clip(g_dense, train_cfg.grad_clip)
-        else:
-            gnorm = jnp.zeros(())
-        upd_d, od = dense_opt.update(g_dense, state["opt_dense"], state["dense"])
-        dense = jax.tree.map(lambda p, u: (p.astype(jnp.float32) + u)
-                             .astype(p.dtype), state["dense"], upd_d)
-        upd_e, oe = embed_opt.update(g_embed, state["opt_embed"], state["embed"])
-        embed = rx.apply_embed_update(state["embed"], upd_e)
+        dense, od, gnorm = dense_update(state, g_dense)
+        with jax.named_scope("embed_update"):
+            upd_e, oe = embed_opt.update(g_embed, state["opt_embed"],
+                                         state["embed"])
+            embed = rx.apply_embed_update(state["embed"], upd_e)
         new_state = {**state, "dense": dense, "embed": embed,
                      "opt_dense": od, "opt_embed": oe,
                      "step": state["step"] + 1, "prefetch": state["prefetch"]}
@@ -90,27 +112,26 @@ def make_step_fns(cfg, train_cfg):
         )(state["dense"], state["embed"], rows_in)
         g_dense, g_embed_direct, g_rows = grads
 
-        # adjoint of the lookup: dense table-shaped grad (sparse content)
-        g_pool = rx.scatter_rows_grad(state["embed"], cfg, batch, g_rows)
-        # tied heads / direct table uses contribute densely
-        g_embed = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
-                               g_pool, g_embed_direct)
+        with jax.named_scope("embed_grad"):
+            # adjoint of the lookup: dense table-shaped grad (sparse content)
+            g_pool = rx.scatter_rows_grad(state["embed"], cfg, batch, g_rows)
+            # tied heads / direct table uses contribute densely
+            g_embed = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
+                                   g_pool, g_embed_direct)
 
-        if train_cfg.grad_clip:
-            g_dense, gnorm = opt.global_norm_clip(g_dense, train_cfg.grad_clip)
-        else:
-            gnorm = jnp.zeros(())
-        upd_d, od = dense_opt.update(g_dense, state["opt_dense"], state["dense"])
-        dense = jax.tree.map(lambda p, u: (p.astype(jnp.float32) + u)
-                             .astype(p.dtype), state["dense"], upd_d)
+        dense, od, gnorm = dense_update(state, g_dense)
 
-        upd_e, oe = embed_opt.update(g_embed, state["opt_embed"], state["embed"])
-        upd_e = rx.constrain_pool(upd_e)
-        embed = rx.apply_embed_update(state["embed"], upd_e)
+        with jax.named_scope("embed_update"):
+            upd_e, oe = embed_opt.update(g_embed, state["opt_embed"],
+                                         state["embed"])
+            upd_e = rx.constrain_pool(upd_e)
+            embed = rx.apply_embed_update(state["embed"], upd_e)
 
         # relaxed prefetch: stale gather (pre-update pool) + correction.
         # No data dependency on `embed` — the scatter never blocks it.
-        rows_next = rx.prefetch_corrected(state["embed"], upd_e, cfg, next_batch)
+        with jax.named_scope("prefetch"):
+            rows_next = rx.prefetch_corrected(state["embed"], upd_e, cfg,
+                                              next_batch)
 
         new_state = {**state, "dense": dense, "embed": embed,
                      "opt_dense": od, "opt_embed": oe,
@@ -118,7 +139,8 @@ def make_step_fns(cfg, train_cfg):
                      "prefetch": {"rows": rows_next}}
         # the batch-aware checkpoint logs exactly the rows this batch touched
         # (known in advance); the manager reads their new values from `embed`
-        ckpt_feed = {"touched": rx.touched_indices(cfg, batch)}
+        with jax.named_scope("ckpt_feed"):
+            ckpt_feed = {"touched": rx.touched_indices(cfg, batch)}
         return new_state, {"loss": loss, "grad_norm": gnorm,
                            "ckpt_feed": ckpt_feed}
 
@@ -160,17 +182,25 @@ def train(cfg, train_cfg, batches, num_steps: int, *, relaxed: bool = True,
     if relaxed and state.get("prefetch") is None:
         state = (jax.jit(warmup) if jit else warmup)(
             state, batches.next(start_step))
+    span = jax.profiler.TraceAnnotation
     for n in range(start_step, start_step + num_steps):
-        batch = batches.next(n)
-        if relaxed:
-            state, metrics = step_relaxed(state, batch, batches.next(n + 1))
-        else:
-            state, metrics = step_strict(state, batch)
-        losses.append(float(metrics["loss"]))
-        if ckpt_manager is not None:
-            ckpt_manager.on_step(n, state, metrics.get("ckpt_feed"))
-        if on_metrics is not None:
-            on_metrics(n, metrics)
+        with jax.profiler.StepTraceAnnotation("repro.train.step", step_num=n):
+            with span("repro.train.next_batch", step=n):
+                batch = batches.next(n)
+                batch_next = batches.next(n + 1) if relaxed else None
+            with span("repro.train.dispatch", step=n):
+                if relaxed:
+                    state, metrics = step_relaxed(state, batch, batch_next)
+                else:
+                    state, metrics = step_strict(state, batch)
+            with span("repro.train.loss_read", step=n):
+                losses.append(float(metrics["loss"]))
+            if ckpt_manager is not None:
+                with span("repro.train.ckpt_on_step", step=n):
+                    ckpt_manager.on_step(n, state, metrics.get("ckpt_feed"))
+            if on_metrics is not None:
+                with span("repro.train.on_metrics", step=n):
+                    on_metrics(n, metrics)
     if ckpt_manager is not None:
         ckpt_manager.flush()
         if own_manager:
