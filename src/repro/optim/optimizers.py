@@ -17,6 +17,10 @@ import jax.numpy as jnp
 class Optimizer(NamedTuple):
     init: Callable[[Any], Any]            # params -> state
     update: Callable[[Any, Any, Any], tuple]  # (grads, state, params) -> (updates, state)
+    # the update of each row reads that row's gradient alone, keeps no state
+    # and maps a zero gradient to a zero update: it may be applied to the
+    # touched rows only (the relaxed step's row-sparse update)
+    row_local: bool = False
 
 
 def sgd(lr: float, momentum: float = 0.0) -> Optimizer:
@@ -32,7 +36,7 @@ def sgd(lr: float, momentum: float = 0.0) -> Optimizer:
                              state, grads)
         return jax.tree.map(lambda m: (-lr * m), new_m), new_m
 
-    return Optimizer(init, update)
+    return Optimizer(init, update, row_local=momentum == 0.0)
 
 
 def adamw(lr: float, b1: float = 0.9, b2: float = 0.95, eps: float = 1e-8,

@@ -15,12 +15,21 @@ relaxed_step (TrainingCXL):
     backward pass. The rows the batch-aware checkpoint logs are idx_N,
     known from the batch before any compute.
 
+    The pool update takes one of two forms, by what the step can observe.
+    DLRM's stacked tables outside a mesh, under a row-local optimizer (plain
+    SGD), carry the update as rows: each table's sorted ids and one f32
+    delta per distinct id (``rx.row_grads``), written into the tables
+    (``rx.write_rows``) and read back by the correction
+    (``rx.row_correction``); the step reports ``rows_updated``. Everything
+    else (other optimizers, LM tables, a mesh) builds a table-shaped f32
+    gradient and update.
+
 Both step functions are pure jit-able pytree->pytree maps; the checkpoint
 manager hooks observe their outputs from the host side.
 
 Tracing. The step programs name their phases with ``jax.named_scope``:
 ``embed_grad`` (the lookup's adjoint), ``dense_update`` (clip, optimizer,
-apply), ``embed_update`` (optimizer, pool layout, apply), ``prefetch``
+apply), ``embed_update`` (optimizer, write into the pool), ``prefetch``
 (relaxed prefetch with correction) and ``ckpt_feed``; the model adds
 ``bottom_mlp``, ``interaction`` and ``top_mlp`` (``models/dlrm.py``), which
 the backward pass inherits. Scopes are metadata only: the compiled program
@@ -104,6 +113,44 @@ def make_step_fns(cfg, train_cfg):
         rows = rx.lookup_rows(state["embed"], cfg, batch0)
         return {**state, "prefetch": {"rows": rows}}
 
+    def table_update(state, batch, next_batch, g_embed_direct, g_rows):
+        """Sparse update through a table-shaped gradient: any optimizer,
+        the LM tables, a mesh."""
+        with jax.named_scope("embed_grad"):
+            # adjoint of the lookup: dense table-shaped grad (sparse content)
+            g_pool = rx.scatter_rows_grad(state["embed"], cfg, batch, g_rows)
+            # tied heads / direct table uses contribute densely
+            g_embed = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
+                                   g_pool, g_embed_direct)
+        with jax.named_scope("embed_update"):
+            upd_e, oe = embed_opt.update(g_embed, state["opt_embed"],
+                                         state["embed"])
+            upd_e = rx.constrain_pool(upd_e)
+            embed = rx.apply_embed_update(state["embed"], upd_e)
+        # relaxed prefetch: stale gather (pre-update pool) + correction.
+        # No data dependency on `embed` — the scatter never blocks it.
+        with jax.named_scope("prefetch"):
+            rows_next = rx.prefetch_corrected(state["embed"], upd_e, cfg,
+                                              next_batch)
+        return embed, oe, rows_next, {}
+
+    def row_update(state, batch, next_batch, g_rows):
+        """Sparse update as (sorted ids, row deltas): DLRM tables under a
+        row-local optimizer, no mesh. The DLRM loss reads the tables only
+        through the carried rows, so they have no direct gradient."""
+        tables = state["embed"]["emb_tables"]
+        with jax.named_scope("embed_grad"):
+            grad = rx.row_grads(tables, batch["sparse"], g_rows)
+        with jax.named_scope("embed_update"):
+            upd, oe = embed_opt.update({"emb_tables": grad.rows},
+                                       state["opt_embed"], None)
+            delta = grad._replace(rows=upd["emb_tables"])
+            embed = {"emb_tables": rx.write_rows(tables, delta)}
+        with jax.named_scope("prefetch"):
+            rows_next = rx.prefetch_rows_corrected(state["embed"], delta, cfg,
+                                                   next_batch)
+        return embed, oe, rows_next, {"rows_updated": delta.count}
+
     def relaxed_step(state, batch, next_batch):
         rows_in = state["prefetch"]["rows"]
 
@@ -112,26 +159,13 @@ def make_step_fns(cfg, train_cfg):
         )(state["dense"], state["embed"], rows_in)
         g_dense, g_embed_direct, g_rows = grads
 
-        with jax.named_scope("embed_grad"):
-            # adjoint of the lookup: dense table-shaped grad (sparse content)
-            g_pool = rx.scatter_rows_grad(state["embed"], cfg, batch, g_rows)
-            # tied heads / direct table uses contribute densely
-            g_embed = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
-                                   g_pool, g_embed_direct)
-
+        if rx.row_update_applies(cfg, state["embed"], embed_opt):
+            embed, oe, rows_next, counts = row_update(
+                state, batch, next_batch, g_rows)
+        else:
+            embed, oe, rows_next, counts = table_update(
+                state, batch, next_batch, g_embed_direct, g_rows)
         dense, od, gnorm = dense_update(state, g_dense)
-
-        with jax.named_scope("embed_update"):
-            upd_e, oe = embed_opt.update(g_embed, state["opt_embed"],
-                                         state["embed"])
-            upd_e = rx.constrain_pool(upd_e)
-            embed = rx.apply_embed_update(state["embed"], upd_e)
-
-        # relaxed prefetch: stale gather (pre-update pool) + correction.
-        # No data dependency on `embed` — the scatter never blocks it.
-        with jax.named_scope("prefetch"):
-            rows_next = rx.prefetch_corrected(state["embed"], upd_e, cfg,
-                                              next_batch)
 
         new_state = {**state, "dense": dense, "embed": embed,
                      "opt_dense": od, "opt_embed": oe,
@@ -142,7 +176,7 @@ def make_step_fns(cfg, train_cfg):
         with jax.named_scope("ckpt_feed"):
             ckpt_feed = {"touched": rx.touched_indices(cfg, batch)}
         return new_state, {"loss": loss, "grad_norm": gnorm,
-                           "ckpt_feed": ckpt_feed}
+                           "ckpt_feed": ckpt_feed, **counts}
 
     return init_fn, strict_step, relaxed_step, warmup
 

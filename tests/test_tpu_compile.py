@@ -7,13 +7,15 @@ results or times. The topology is described inside a fixture, never at
 import: only one process at a time may load the TPU library.
 """
 import os
+import re
 
 import numpy as np
 import pytest
 
 R, D = 1_000_000, 32          # one DLRM-RM1 table (configs/dlrm_rm1.py)
 B, L = 256, 80                # batch (sim/models_rm.py), lookups per table
-STEP_BYTES_MAX = 10e9         # relaxed RM1 step: args + outputs + temps
+STEP_BYTES_MAX = 5e9          # relaxed RM1 step: args + outputs + temps
+STEP_TEMPS_MAX = 3.5e9        # relaxed RM1 step: temps alone
 
 
 @pytest.fixture(scope="module")
@@ -44,7 +46,9 @@ def _on(sharding, tree):
         tree)
 
 
-def test_relaxed_rm1_step_fits_one_v5e(one_chip):
+@pytest.fixture(scope="module")
+def relaxed_rm1(one_chip):
+    """The relaxed RM1 step compiled for one described v5e chip."""
     import jax
     import jax.numpy as jnp
 
@@ -64,8 +68,12 @@ def test_relaxed_rm1_step_fits_one_v5e(one_chip):
     state = jax.eval_shape(warmup, jax.eval_shape(
         init_fn, jax.random.PRNGKey(0)), batch)
     batch = _on(one_chip, batch)
-    compiled = jax.jit(relaxed_step).lower(
+    return T, jax.jit(relaxed_step).lower(
         _on(one_chip, state), batch, batch).compile()
+
+
+def test_relaxed_rm1_step_fits_one_v5e(relaxed_rm1):
+    _, compiled = relaxed_rm1
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes)
@@ -73,6 +81,26 @@ def test_relaxed_rm1_step_fits_one_v5e(one_chip):
         f"relaxed RM1 step needs {total / 1e9:.2f} GB (args "
         f"{m.argument_size_in_bytes}, outputs {m.output_size_in_bytes}, "
         f"temps {m.temp_size_in_bytes})")
+
+
+def test_relaxed_rm1_step_updates_rows_not_tables(relaxed_rm1):
+    """The sparse update builds nothing of the tables' shape in f32, and
+    the only loops over a whole table are the two that relay it out for the
+    prefetch's stale gather (``embedding_ops.bag_lookup``'s flat view)."""
+    T, compiled = relaxed_rm1
+    hlo = compiled.as_text()
+    n = T * R * D
+    f32_tables = [s for s in re.findall(r"f32\[([\d,]+)\]", hlo)
+                  if np.prod([int(x) for x in s.split(",")]) == n]
+    assert not f32_tables
+    loops = [line for line in hlo.splitlines()
+             if re.search(r"= \(.*\) while\(", line)
+             and any(np.prod([int(x) for x in s.split(",")]) == n
+                     for s in re.findall(r"\[([\d,]+)\]", line))]
+    assert len(loops) <= 2, loops
+    assert "tpu_custom_call" in hlo          # the rows' streaming write
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps <= STEP_TEMPS_MAX, f"temps {temps / 1e9:.2f} GB"
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
