@@ -88,13 +88,23 @@ def spec_for(config: str, smoke: bool = False):
 
 
 def smoke_config(config: dict) -> dict:
-    """The configuration at the program's smoke preset: 2,048 rows a table,
-    a narrow MLP in float32, batch 16."""
-    sizes = dict(config["sizes"], rows_per_table=2048,
-                 bottom_mlp=[13, 64, config["sizes"]["embed_dim"]],
-                 top_mlp=[32, 1], dtype="float32", batch=16)
-    return dict(config, smoke=True, sizes=sizes)
-
+    """The configuration at the program's smoke preset. The file's
+    ``"smoke"`` block gives the sizes that change there. A file with
+    scalar tables (``bench/layout.py``) that has none gets those of DLRM
+    RM1 and RM3: 2,048 rows a table, a narrow MLP in float32, batch 16; a
+    file with per-table lists has to bring its own."""
+    from bench.layout import layout
+    sizes = config["sizes"]
+    if "smoke" in config:
+        smoke = config["smoke"]
+    elif layout(sizes).pooled:
+        raise ValueError(f"{config['name']}.json lists its tables and has "
+                         f"no \"smoke\" block")
+    else:
+        smoke = {"rows_per_table": 2048,
+                 "bottom_mlp": [13, 64, sizes["embed_dim"]],
+                 "top_mlp": [32, 1], "dtype": "float32", "batch": 16}
+    return dict(config, smoke_preset=True, sizes=dict(sizes, **smoke))
 
 if __name__ == "__main__":
     sys.exit(main())

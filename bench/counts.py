@@ -1,7 +1,9 @@
 """Operations and bytes a DLRM training step requires, from its shapes.
 
-Independent of how the program computes the step. Counts follow the shape
-arithmetic of ``repro.sim.models_rm``, extended to the backward pass:
+The counts of the pairwise-dot DLRM, which a configuration file gets where
+it names no ``"counts"`` module of its own. Independent of how the program
+computes the step. Counts follow the shape arithmetic of
+``repro.sim.models_rm``, extended to the backward pass:
 
 - model FLOPs per sample (forward + backward of the bottom MLP, the bag
   sums, the pairwise interaction and the top MLP; a multiply-add is 2);
@@ -15,6 +17,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from bench.layout import layout
+
 ADAM_FLOPS_PER_PARAM = 12      # two moments, bias corrections, the step
 SGD_FLOPS_PER_ELEM = 2         # scale the gradient, add it
 
@@ -24,7 +28,7 @@ def _pairs(dims):
 
 
 def top_dims(sizes: dict) -> tuple:
-    F = sizes["num_tables"] + 1
+    F = layout(sizes).num_tables + 1
     return (sizes["embed_dim"] + F * (F - 1) // 2,) + tuple(sizes["top_mlp"])
 
 
@@ -37,8 +41,8 @@ def dense_params(sizes: dict) -> int:
 
 def model_flops_per_sample(sizes: dict) -> float:
     """Forward + backward FLOPs of one sample."""
-    T, L, d = (sizes["num_tables"], sizes["lookups_per_table"],
-               sizes["embed_dim"])
+    lay, d = layout(sizes), sizes["embed_dim"]
+    T = lay.num_tables
     F = T + 1
     fwd = bwd = 0.0
     for i, (a, b) in enumerate(_pairs(tuple(sizes["bottom_mlp"]))):
@@ -51,20 +55,20 @@ def model_flops_per_sample(sizes: dict) -> float:
     pairs = F * (F - 1) // 2
     fwd += 2 * pairs * d
     bwd += 4 * pairs * d            # both operands of every dot
-    fwd += T * (L - 1) * d          # bag sums
-    bwd += T * L * d                # each looked-up row gets its bag's grad
+    fwd += (lay.lookups - T) * d    # bag sums
+    bwd += lay.lookups * d          # each looked-up row gets its bag's grad
     return fwd + bwd
 
 
 def step_work(sizes: dict, unique_rows: int) -> tuple[float, float]:
     """(FLOPs, bytes) one step requires."""
-    B, T, L, d = (sizes["batch"], sizes["num_tables"],
-                  sizes["lookups_per_table"], sizes["embed_dim"])
+    B, d = sizes["batch"], sizes["embed_dim"]
     P = dense_params(sizes)
     width = np.dtype(sizes["dtype"]).itemsize
     flops = (B * model_flops_per_sample(sizes) + ADAM_FLOPS_PER_PARAM * P
              + SGD_FLOPS_PER_ELEM * unique_rows * d)
-    nbytes = (4 * B * T * L + 4 * B * sizes["num_dense"] + 4 * B
+    nbytes = (4 * B * layout(sizes).lookups + 4 * B * sizes["num_dense"]
+              + 4 * B
               + 2 * unique_rows * d * width
               + 2 * P * width + 2 * 2 * P * 4)
     return flops, nbytes

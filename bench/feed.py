@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from bench.layout import layout
+
 
 class ZipfSampler:
     """Zipf(alpha) row ids in [0, num_rows), hot rows spread by a hash."""
@@ -23,8 +25,9 @@ class ZipfSampler:
         self.cdf = np.cumsum(probs)
         self.num_rows = num_rows
 
-    def draw(self, rng: np.random.Generator, shape) -> np.ndarray:
-        idx = np.searchsorted(self.cdf, rng.random(size=shape))
+    def ids(self, u: np.ndarray) -> np.ndarray:
+        """The row ids of uniforms ``u`` in [0, 1), elementwise."""
+        idx = np.searchsorted(self.cdf, u)
         n = self.num_rows
         perm_seed = np.uint64(n * 2654435761 % (2**31))
         rows = (idx.astype(np.uint64) * np.uint64(2654435761)
@@ -34,27 +37,35 @@ class ZipfSampler:
 
 def make_ring(sizes: dict, traffic: dict, seed: int) -> list[dict]:
     """``traffic["ring"]`` distinct host batches, the same for the same seed:
-    dense features N(0, 1), labels Bernoulli(1/2), sparse ids Zipf."""
+    dense features N(0, 1), labels Bernoulli(1/2), sparse ids Zipf in the
+    layout of ``bench/layout.py``. A batch's ids come from one uniform draw
+    over its ``sparse`` shape; each table's columns are mapped to ids by a
+    sampler of that table's rows."""
     rng = np.random.default_rng(seed)
-    sampler = ZipfSampler(sizes["rows_per_table"], traffic["zipf_alpha"])
+    lay = layout(sizes)
     B = sizes["batch"]
-    shape = (B, sizes["num_tables"], sizes["lookups_per_table"])
+    samplers = {R: ZipfSampler(R, traffic["zipf_alpha"])
+                for R in set(lay.rows)}
+    ends = np.cumsum(lay.pooling)
+
+    def sparse():
+        u = rng.random(size=lay.batch_shape(B)).reshape(B, -1)
+        return np.concatenate(
+            [samplers[R].ids(u[:, e - L:e])
+             for R, L, e in zip(lay.rows, lay.pooling, ends, strict=True)],
+            axis=1).reshape(lay.batch_shape(B))
+
     ring = []
     for _ in range(traffic["ring"]):
         dense = rng.standard_normal((B, sizes["num_dense"])).astype(np.float32)
         labels = (rng.random(B) < 0.5).astype(np.float32)
-        ring.append({"dense": dense, "sparse": sampler.draw(rng, shape),
-                     "labels": labels})
+        ring.append({"dense": dense, "sparse": sparse(), "labels": labels})
     return ring
 
 
 def unique_rows(sizes: dict, batch: dict) -> int:
     """Distinct (table, row) pairs one batch touches."""
-    R = sizes["rows_per_table"]
-    sparse = batch["sparse"]
-    flat = sparse + (np.arange(sparse.shape[1], dtype=np.int64)[None, :, None]
-                     * R)
-    return int(np.unique(flat).size)
+    return int(np.unique(layout(sizes).flat_ids(batch["sparse"])).size)
 
 
 class Feed:

@@ -15,14 +15,30 @@ the measured call's first step traces and loads the step again: the window
 opens when that step's ``on_metrics`` runs. It closes at the first
 ``on_metrics`` after ``seconds`` have passed and, in checkpointing cells,
 after ``flush()`` has returned, so deferred checkpoint work is paid inside.
+
+What belongs to one configuration comes from its file: the tables and
+their pooling (``sizes``, read through ``bench/layout.py``), the plain
+reference (``"reference"``, a module under ``bench/``), the counts of its
+required work (``"counts"``, default ``counts.py``), which program fields
+its sizes are held to (``"program_fields"``) and its smoke sizes
+(``"smoke"``, ``bench/control.py``). A reference module gives
+``readings(key, sizes, recipe, batches, *, store, act, rows)``,
+``leaf_names(tree)``, ``diff_norm(a, b)``,
+``program_grad_norms(opt_dense, recipe)``, the program's first dense
+gradient read from its optimizer state, and ``RECIPES``, the recipes it
+implements and what each key sets in the program (``train_fields``);
+a counts module ``model_flops_per_sample(sizes)`` and
+``step_min_seconds(sizes, unique_rows, peaks)``.
 """
 from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib.util
 import json
 import math
 import os
+import re
 import shutil
 import sys
 import time
@@ -31,6 +47,7 @@ from pathlib import Path
 import numpy as np
 
 from bench import checks, feed
+from bench.layout import layout
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = Path(__file__).resolve().parent
@@ -66,6 +83,18 @@ class Spec:
     def name(self) -> str:
         return self.cell["name"]
 
+    @property
+    def reference(self):
+        """The plain reference module the configuration file names."""
+        return load_module(self.config["reference"],
+                           f"{self.config['name']}.json's reference")
+
+    @property
+    def counts(self):
+        """The counts of required work the configuration file names."""
+        return load_module(self.config.get("counts", "counts.py"),
+                           f"{self.config['name']}.json's counts")
+
     def metrics_for(self, trace: bool) -> list[dict]:
         """The metrics this cell prints: end-to-end without the trace,
         per-layer with it, each only where its ``workloads`` list the cell."""
@@ -85,8 +114,30 @@ def load_spec(workload: str, bench: dict | None = None) -> Spec:
     config = json.loads((ROOT / conf["file"]).read_text())
     traffic = json.loads(
         (BENCH / "traffic" / f"{cell['traffic']}.json").read_text())
-    return Spec(cell, config, traffic, bench["end_to_end"],
+    spec = Spec(cell, config, traffic, bench["end_to_end"],
                 bench["per_layer"])
+    layout(config["sizes"])
+    train_fields(config["recipe"], spec.reference)
+    spec.counts  # noqa: B018  a wrong path fails here
+    return spec
+
+
+def load_module(rel: str, what: str):
+    """The Python file at ``bench/<rel>``, loaded once per process."""
+    path = (BENCH / rel).resolve()
+    if not path.is_file() or not path.is_relative_to(BENCH):
+        raise FileNotFoundError(f"{what} {rel!r} is no file under bench/")
+    name = "bench_file_" + re.sub(r"\W", "_", rel)
+    if name not in sys.modules:
+        mod_spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        sys.modules[name] = mod
+        try:
+            mod_spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
 
 
 def peaks_for(kind: str) -> dict:
@@ -180,22 +231,21 @@ class Probe:
 
 class SetupReadings:
     """The program's numbers from the first three steps of the warm call:
-    losses, the first dense gradient as AdamW holds it after step 1
-    (m / (1 - beta1)), and every leaf's change after step 3. The table's
-    change is taken over the rows the three batches touch, the only rows an
-    SGD step on their gradients can move."""
+    losses, the first dense gradient as the reference module reads it from
+    the optimizer state after step 1 (for AdamW m / (1 - beta1)), and every
+    leaf's change after step 3. The table's change is taken over the rows
+    the three batches touch, the only rows a row-local step on their
+    gradients can move."""
 
-    def __init__(self, state0, ring, sizes, beta1):
+    def __init__(self, state0, ring, sizes, recipe, ref):
         import jax
         import jax.numpy as jnp
-        from bench.references import dlrm as ref
         self.ref = ref
-        self.beta1 = beta1
+        self.recipe = recipe
         self.dense0 = state0["dense"]
-        T, R = sizes["num_tables"], sizes["rows_per_table"]
-        off = np.arange(T, dtype=np.int64)[None, :, None] * R
+        flat_ids = layout(sizes).flat_ids
         ids = np.unique(np.concatenate(
-            [(b["sparse"] + off).reshape(-1) for b in ring[:3]]))
+            [flat_ids(b["sparse"]).reshape(-1) for b in ring[:3]]))
         self.ids = jnp.asarray(ids.astype(np.int32))
         tab = state0["embed"]["emb_tables"]
         self.rows0 = jnp.take(tab.reshape(-1, tab.shape[-1]), self.ids,
@@ -212,19 +262,17 @@ class SetupReadings:
     def on_step(self, step, state):
         import jax.numpy as jnp
         if step == 0:
-            m = self.ref.leaf_names(state["opt_dense"]["m"])
-            self.grad_norms = {
-                name: float(self.ref._leaf_norm(v)) / (1.0 - self.beta1)
-                for name, v in m.items()}
+            self.grad_norms = self.ref.program_grad_norms(state["opt_dense"],
+                                                          self.recipe)
         elif step == 2:
             now = self.ref.leaf_names(state["dense"])
             was = self.ref.leaf_names(self.dense0)
-            self.change_norms = {n: float(self.ref._diff_norm(now[n], was[n]))
+            self.change_norms = {n: float(self.ref.diff_norm(now[n], was[n]))
                                  for n in now}
             tab = state["embed"]["emb_tables"]
             rows = jnp.take(tab.reshape(-1, tab.shape[-1]), self.ids, axis=0)
             self.change_norms["table"] = float(
-                self.ref._diff_norm(rows, self.rows0))
+                self.ref.diff_norm(rows, self.rows0))
             self.dense0 = self.rows0 = self.ids = None
 
     def result(self) -> dict:
@@ -291,35 +339,68 @@ class Run:
         return self.sizes["batch"]
 
 
-def _program(spec: Spec, pool_dir: Path):
-    """The program's model and training configuration for this cell."""
-    from repro.configs import get_arch
-    from repro.configs.base import CheckpointConfig, TrainConfig
-    c, t = spec.config, spec.traffic
-    cfg = get_arch(c["arch"], smoke=bool(c.get("smoke", False))).model
-    s = c["sizes"]
-    have = {"num_tables": cfg.dlrm_num_tables,
+def train_fields(recipe: dict, ref) -> dict:
+    """The program's ``TrainConfig`` fields for ``recipe``, as the reference
+    module's ``RECIPES`` says: keyed by the (dense, embedding) optimizers a
+    recipe names, each entry maps every other key of the recipe to the
+    field it sets (``None``: a constant of the program's optimizer that the
+    reference alone reads) and holds the ``fixed`` fields the reference
+    implements at one value. Raises ValueError where the reference
+    implements no such recipe, or the recipe lacks a key that it uses or
+    has one that reaches neither the program nor the reference."""
+    opts = (recipe.get("optimizer"), recipe.get("embed_optimizer"))
+    if opts not in ref.RECIPES:
+        raise ValueError(f"the reference implements no recipe of the "
+                         f"optimizers {opts}, only {list(ref.RECIPES)}")
+    keys, fixed = ref.RECIPES[opts]["keys"], ref.RECIPES[opts]["fixed"]
+    given = set(recipe) - {"optimizer", "embed_optimizer"}
+    if given != set(keys):
+        raise ValueError(f"the recipe of {opts} lacks "
+                         f"{sorted(set(keys) - given)} and has "
+                         f"{sorted(given - set(keys))} unused")
+    return {"optimizer": opts[0], "embed_optimizer": opts[1], **fixed,
+            **{f: recipe[k] for k, f in keys.items() if f}}
+
+
+def _dlrm_fields(cfg) -> dict:
+    """The sizes a file without ``program_fields`` is held to."""
+    return {"num_tables": cfg.dlrm_num_tables,
             "rows_per_table": cfg.dlrm_rows_per_table,
             "embed_dim": cfg.dlrm_bottom_mlp[-1],
             "lookups_per_table": max(1, cfg.dlrm_num_sparse),
             "bottom_mlp": list(cfg.dlrm_bottom_mlp),
             "top_mlp": list(cfg.dlrm_top_mlp),
             "num_dense": cfg.dlrm_num_dense, "dtype": cfg.dtype}
-    diff = {k: (v, s[k]) for k, v in have.items() if s[k] != v}
+
+
+def _program(spec: Spec, pool_dir: Path):
+    """The program's model and training configuration for this cell. The
+    file's ``program_fields`` map (size key -> attribute of the program's
+    model config; tuples compare as lists) says which sizes are held to the
+    program's; without it, those of ``_dlrm_fields``."""
+    from repro.configs import get_arch
+    from repro.configs.base import CheckpointConfig, TrainConfig
+    c, t = spec.config, spec.traffic
+    cfg = get_arch(c["arch"], smoke=bool(c.get("smoke_preset"))).model
+    s = c["sizes"]
+    if "program_fields" in c:
+        have = {k: getattr(cfg, attr)
+                for k, attr in c["program_fields"].items()}
+        have = {k: list(v) if isinstance(v, tuple) else v
+                for k, v in have.items()}
+    else:
+        have = _dlrm_fields(cfg)
+    diff = {k: (v, s.get(k)) for k, v in have.items() if s.get(k) != v}
     if diff:
         raise ValueError(f"program config {c['arch']} differs from "
                          f"{c['name']}.json: {diff}")
-    r = c["recipe"]
     ckpt = CheckpointConfig(enabled=False)
     if t["checkpoint"]:
         ckpt = CheckpointConfig(
             directory=str(pool_dir), dense_interval=t["dense_interval"],
             pool_backend=t["pool_backend"], pool_compress=t["compress"])
-    tc = TrainConfig(learning_rate=r["lr"], embed_learning_rate=r["embed_lr"],
-                     optimizer=r["optimizer"],
-                     embed_optimizer=r["embed_optimizer"], beta1=r["beta1"],
-                     beta2=r["beta2"], grad_clip=r["grad_clip"],
-                     checkpoint=ckpt)
+    tc = TrainConfig(checkpoint=ckpt,
+                     **train_fields(c["recipe"], spec.reference))
     return cfg, tc
 
 
@@ -343,7 +424,8 @@ def program_readings(spec: Spec, seed: int) -> dict:
     from repro.training import train_loop
     cfg, tc, ring, batches, box = _start(spec, seed, SCRATCH / "unused")
     probe = Probe(None, 0)
-    setup = SetupReadings(box["state"], ring, spec.config["sizes"], tc.beta1)
+    setup = SetupReadings(box["state"], ring, spec.config["sizes"],
+                          spec.config["recipe"], spec.reference)
     probe.watch = setup.on_step
     train_loop.train(cfg, tc, batches, 3,
                      relaxed=spec.traffic["schedule"] == "relaxed",
@@ -380,7 +462,8 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, *,
                                     embed_init=box["state"]["embed"])
             log("checkpoint pool holds the initial table")
         probe = Probe(mgr, tc.checkpoint.dense_interval)
-        setup = SetupReadings(box["state"], ring, sizes, tc.beta1)
+        setup = SetupReadings(box["state"], ring, sizes,
+                              spec.config["recipe"], spec.reference)
         probe.watch = setup.on_step
         relaxed = traffic["schedule"] == "relaxed"
         warm = traffic["warm_steps"]
@@ -415,7 +498,8 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, *,
         if tracer:
             tracer.stop()
         log(f"window closed: {len(window.steps)} steps in "
-            f"{window.length:.3f}s")
+            f"{window.length:.3f}s, longest step "
+            f"{max(window.step_times(), default=0.0):.3f}s")
         n_compiles = compiles.n - compiles0
         pool_bytes = _pool_bytes(mgr) - pool0
 
@@ -468,10 +552,9 @@ def _warm_row_gathers(state, ring, sizes):
     import jax.numpy as jnp
     tab = state["embed"]["emb_tables"]
     flat = tab.reshape(-1, tab.shape[-1])
-    off = np.arange(sizes["num_tables"])[None, :, None] * \
-        sizes["rows_per_table"]
+    flat_ids = layout(sizes).flat_ids
     for b in ring:
-        ids = np.unique((off + b["sparse"]).reshape(-1))
+        ids = np.unique(flat_ids(b["sparse"]).reshape(-1))
         jax.block_until_ready(jnp.take(flat, jnp.asarray(ids), axis=0))
 
 
@@ -544,21 +627,14 @@ def reference_readings(spec: Spec, seed: int, ring: list, *, store=None,
     """The plain reference's first three steps from the same seed; with
     ``store``/``act`` lowered it is the control, with ``rows`` a planted
     fault (part of each batch left out)."""
-    from bench.references import dlrm as ref
     sizes = spec.config["sizes"]
-    return ref.readings(key_for(seed), sizes, spec.config["recipe"],
-                        ring[:3], store=store or sizes["dtype"], act=act,
-                        rows=rows)
+    return spec.reference.readings(
+        key_for(seed), sizes, spec.config["recipe"], ring[:3],
+        store=store or sizes["dtype"], act=act, rows=rows)
 
 
 def load_reader(name: str):
-    import importlib.util
-    path = BENCH / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(f"metrics/{name}.py", "the metric reader").read
 
 
 def _result(spec, run: Run, trace: bool, devs, peak: int,

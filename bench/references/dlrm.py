@@ -36,6 +36,15 @@ from jax import lax
 F32 = jnp.float32
 HI = lax.Precision.HIGHEST
 
+# The recipes implemented here, by their (dense, embedding) optimizers: the
+# TrainConfig field each recipe key sets (None: the program's AdamW holds
+# eps constant) and the fields held to the one value implemented here.
+RECIPES = {("adamw", "sgd"): {
+    "keys": {"lr": "learning_rate", "beta1": "beta1", "beta2": "beta2",
+             "eps": None, "grad_clip": "grad_clip",
+             "embed_lr": "embed_learning_rate"},
+    "fixed": {"weight_decay": 0.0}}}
+
 
 def top_dims(sizes: dict) -> tuple:
     F = sizes["num_tables"] + 1
@@ -131,12 +140,12 @@ def step(params, adam, batch, *, store, act, hp):
 
 
 @jax.jit
-def _leaf_norm(a):
+def leaf_norm(a):
     return jnp.sqrt(jnp.sum(jnp.square(a.astype(F32))))
 
 
 @jax.jit
-def _diff_norm(a, b):
+def diff_norm(a, b):
     return jnp.sqrt(jnp.sum(jnp.square(a.astype(F32) - b.astype(F32))))
 
 
@@ -147,6 +156,14 @@ def leaf_names(tree) -> dict:
         out[".".join(str(getattr(k, "key", getattr(k, "idx", k)))
                      for k in path)] = leaf
     return out
+
+
+def program_grad_norms(opt_dense, recipe: dict) -> dict:
+    """Each dense leaf's first gradient, after clipping, as the program's
+    AdamW state holds it after one step (m / (1 - beta1)): its norm by leaf
+    name, as ``readings`` names the reference's."""
+    return {name: float(leaf_norm(m)) / (1.0 - recipe["beta1"])
+            for name, m in leaf_names(opt_dense["m"]).items()}
 
 
 def readings(key, sizes: dict, recipe: dict, batches: list, *,
@@ -176,10 +193,10 @@ def readings(key, sizes: dict, recipe: dict, batches: list, *,
             hp=hp)
         losses.append(float(loss))
         if i == 0:
-            grad_norms = {k: float(_leaf_norm(g))
+            grad_norms = {k: float(leaf_norm(g))
                           for k, g in leaf_names(g_dense).items()}
             grad_norms["table"] = float(g_tab)
     before, after = leaf_names(first), leaf_names(params)
-    change = {k: float(_diff_norm(after[k], before[k])) for k in after}
+    change = {k: float(diff_norm(after[k], before[k])) for k in after}
     return {"losses": losses, "grad_norms": grad_norms,
             "change_norms": change}

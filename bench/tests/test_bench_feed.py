@@ -9,7 +9,7 @@ from bench import feed
 def test_sampler_draws_what_the_programs_sampler_draws():
     from repro.data.synthetic import zipf_indices
     s = feed.ZipfSampler(5000, 1.05)
-    got = s.draw(np.random.default_rng(3), (4, 7, 9))
+    got = s.ids(np.random.default_rng(3).random(size=(4, 7, 9)))
     want = zipf_indices(np.random.default_rng(3), (4, 7, 9), 5000, 1.05)
     np.testing.assert_array_equal(got, want)
     assert got.dtype == np.int32 and got.min() >= 0 and got.max() < 5000
@@ -38,7 +38,8 @@ def test_ring_is_a_function_of_the_seed(seed):
 
 def test_unique_rows_counts_table_row_pairs():
     batch = {"sparse": np.array([[[1, 1, 2], [1, 3, 3]]], np.int32)}
-    assert feed.unique_rows({"rows_per_table": 10}, batch) == 4
+    sizes = {"num_tables": 2, "rows_per_table": 10, "lookups_per_table": 3}
+    assert feed.unique_rows(sizes, batch) == 4
 
 
 def test_feed_hands_device_arrays_and_cycles():

@@ -2,11 +2,13 @@
 mix and metric is found by its name, and the configurations are the
 program's own."""
 import json
+import math
 import re
 
 import pytest
 
 from bench import harness
+from bench.layout import layout
 from bench.tests.helpers import PENDING, ROOT, benchmark, spec_of
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -67,10 +69,19 @@ def test_configuration_file_matches_the_programs_config(conf):
     configuration; the reference reads the same file."""
     from bench import control
     spec = control.spec_for(conf["name"])
-    cfg, _ = harness._program(spec, ROOT / "bench" / ".scratch" / "unused")
+    cfg, tc = harness._program(spec, ROOT / "bench" / ".scratch" / "unused")
     data = json.loads((ROOT / conf["file"]).read_text())
     assert data["name"] == conf["name"] and data["reduced"] == conf["reduced"]
-    assert cfg.dlrm_rows_per_table == data["sizes"]["rows_per_table"]
+    # the program's tables, flattened to (-1, d), hold every table's rows
+    import jax
+
+    from repro.training import train_loop
+    state = jax.eval_shape(train_loop.make_step_fns(cfg, tc)[0],
+                           harness.key_for(0))
+    tab = state["embed"]["emb_tables"].shape
+    lay = layout(data["sizes"])
+    assert math.prod(tab[:-1]) == lay.total_rows
+    assert tab[-1] == data["sizes"]["embed_dim"]
     wrong = dict(spec.config, sizes=dict(spec.config["sizes"], embed_dim=8))
     spec.config = wrong
     with pytest.raises(ValueError, match="differs"):
