@@ -150,6 +150,14 @@ def lookup(table, ids, *, mode: Optional[str] = None):
         out_specs=P(*batch_spec, None))(table, ids)
 
 
+def _stacked_bags(tables, ids, combine: str):
+    """Bags of ``rows[b, t, l] = tables[t, ids[b, t, l]]``, reduced over l:
+    one gather whose slices are whole rows of the ``(T, R, d)`` tables.
+    Ids lie in ``[0, R)``; XLA clamps one that does not."""
+    rows = tables[jnp.arange(tables.shape[0])[None, :, None], ids]
+    return rows.sum(axis=2) if combine == "sum" else rows.mean(axis=2)
+
+
 def bag_lookup(tables, ids, *, mode: Optional[str] = None, combine: str = "sum"):
     """DLRM multi-table bag lookup with near-data reduction.
 
@@ -160,6 +168,12 @@ def bag_lookup(tables, ids, *, mode: Optional[str] = None, combine: str = "sum")
     rows it holds for each bag locally and the partial bag vectors are
     psum-combined — exactly the CXL-MEM adder array. Link bytes: B*T*d,
     independent of L (the paper's headline traffic saving).
+
+    Unsharded (and ``table_gather``), the rows come from the stacked tables
+    as stored, indexed by table and row together (``_stacked_bags``). No
+    form views the tables as a flat ``[T·R, d]`` array: on a TPU the stored
+    layout is rows-minor, and such a view relays out every table on every
+    call.
     """
     ctx = sharding.current()
     mode = mode or current_mode()
@@ -172,21 +186,14 @@ def bag_lookup(tables, ids, *, mode: Optional[str] = None, combine: str = "sum")
             lambda i: mir.bag_lookup(i, combine).astype(tables.dtype),
             (ids.shape[0], T, d), tables.dtype, ids)
     if ctx is None:
-        rows = jnp.take(tables.reshape(T * R, d),
-                        (ids + jnp.arange(T)[None, :, None] * R).reshape(-1),
-                        axis=0)
-        rows = rows.reshape(*ids.shape, d)
-        return rows.sum(axis=2) if combine == "sum" else rows.mean(axis=2)
+        return _stacked_bags(tables, ids, combine)
 
     tp_ax = ctx.rules.get("table_rows")
     tp = _axis_size(ctx.mesh, tp_ax)
     if tp == 1 or mode == "table_gather":
         t = jax.lax.with_sharding_constraint(
             tables, NamedSharding(ctx.mesh, P()))
-        rows = jnp.take(t.reshape(T * R, d),
-                        (ids + jnp.arange(T)[None, :, None] * R).reshape(-1),
-                        axis=0).reshape(*ids.shape, d)
-        out = rows.sum(axis=2) if combine == "sum" else rows.mean(axis=2)
+        out = _stacked_bags(t, ids, combine)
         return sharding.constrain(out, ("batch", None, "embed"))
 
     dp_ax = ctx.rules.get("batch")

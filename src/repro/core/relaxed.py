@@ -219,8 +219,10 @@ def write_rows(tables, delta: RowUpdate):
     bitwise. On a TPU one streaming pass of ``kernels.row_merge`` writes
     them (an XLA scatter pays an HBM round trip per row); elsewhere, or
     where a table's slots do not fit the kernel's VMEM, ``_write_rows_xla``.
-    Neither views the tables as a flat ``[T·R, d]`` array: the stored
-    layout is rows-minor, and such a view relays out the whole table."""
+    No part of the row path (this write, ``row_correction``, nor the stale
+    gather of ``embedding_ops.bag_lookup``) views the tables as a flat
+    ``[T·R, d]`` array: the stored layout is rows-minor, and such a view
+    relays out the whole table."""
     _, W = delta.ids.shape
     if not row_merge.fits(W, tables.shape[-1]):
         return _write_rows_xla(tables, delta)

@@ -89,3 +89,59 @@ def test_property_bag_sum(seed, b, k):
             for li in range(k):
                 want[bi, t] += np.asarray(tables)[t, int(ids[bi, t, li])]
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("T", [1, 5])
+@pytest.mark.parametrize("combine", ["sum", "mean"])
+def test_bag_lookup_is_per_table_gather_and_sum(rng, combine, T, dtype):
+    """Bitwise a per-table gather, summed over the bag in order in f32 and
+    rounded once, as ``jnp.sum`` / ``jnp.mean`` do for bf16. L is a power
+    of two, so the mean's division is exact, whether XLA divides or
+    multiplies by the reciprocal."""
+    R, d, B, L = 40, 8, 3, 8
+    tables = jnp.asarray(rng.standard_normal((T, R, d)), dtype)
+    ids = rng.integers(0, R, (B, T, L)).astype(np.int32)
+    ids[0, :, 0], ids[-1, :, -1] = 0, R - 1          # both ends of a table
+    got = eo.bag_lookup(tables, jnp.asarray(ids), combine=combine)
+    tab = np.asarray(tables.astype(jnp.float32))
+    want = np.zeros((B, T, d), np.float32)
+    for t in range(T):
+        for li in range(L):
+            want[:, t] += tab[t, ids[:, t, li]]
+    if combine == "mean":
+        want /= np.float32(L)
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(jnp.asarray(want, dtype)
+                                             .astype(jnp.float32)))
+
+
+def _reshapes(jaxpr):
+    """Output shapes of every ``reshape`` in ``jaxpr`` and its sub-jaxprs."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "reshape":
+            out.extend(v.aval.shape for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_reshapes(sub))
+    return out
+
+
+@pytest.mark.parametrize("mode", [None, "table_gather"])
+def test_bag_lookup_never_views_the_tables_flat(rng, mode):
+    """The stale gather takes rows from the ``(T, R, d)`` tables as stored:
+    a ``[T·R, d]`` view would relay out every table on a TPU."""
+    T, R, d = 4, 32, 8
+    tables = jnp.zeros((T, R, d), jnp.bfloat16)
+    ids = jnp.asarray(rng.integers(0, R, (2, T, 5)).astype(np.int32))
+    fn = jax.jit(lambda t, i: eo.bag_lookup(t, i))
+    if mode is None:
+        jaxpr = jax.make_jaxpr(fn)(tables, ids).jaxpr
+    else:
+        with sharding.use_sharding(make_local_mesh(model_parallel=1),
+                                   {"batch": "data"}):
+            with eo.lookup_mode(mode):
+                jaxpr = jax.make_jaxpr(fn)(tables, ids).jaxpr
+    flat = [s for s in _reshapes(jaxpr) if s and s[0] == T * R]
+    assert not flat, flat
